@@ -13,6 +13,10 @@ Observability (repro/telemetry):
 * ``--profile-dir DIR`` — wrap each suite in a ``jax.profiler`` trace and
   per-suite wall-clock spans (printed as a phase table at the end).
 * ``--smoke`` — reduced iteration counts for suites that support it (CI).
+
+A suite that raises is logged with its traceback and printed as a
+``<suite>_FAILED`` row; the other suites still run, and the command then
+exits 1.
 """
 from __future__ import annotations
 
@@ -59,9 +63,11 @@ def main() -> None:
     import importlib
 
     sys.path.insert(0, "src")  # python -m benchmarks.run without PYTHONPATH
+    from repro.launch.cache import use_compile_cache
     from repro.telemetry import PhaseTracer, export_bench
     from repro.utils import get_logger
 
+    use_compile_cache()
     log = get_logger("repro.bench")
     tracer = PhaseTracer(profile_dir=args.profile_dir or None)
     if args.profile_dir:
@@ -69,6 +75,7 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for name, modname in MODULES:
         if only and name not in only:
             continue
@@ -80,9 +87,10 @@ def main() -> None:
             for row in rows:
                 print(row)
                 sys.stdout.flush()
-        except Exception as e:  # noqa: BLE001
-            log.error("suite %s failed: %s: %s", name, type(e).__name__, e)
+        except Exception as e:  # noqa: BLE001 - report, run the rest, exit 1
+            log.exception("suite %s failed", name)
             print(f"{name}_FAILED,0,{type(e).__name__}:{e}")
+            failed.append(name)
         if args.out_dir and rows:
             path = export_bench(name, rows, out_dir=args.out_dir,
                                 meta={"smoke": bool(args.smoke)})
@@ -93,6 +101,9 @@ def main() -> None:
     if tracer.spans:
         log.info("suite wall clock:\n%s", tracer.summary())
     log.info("total_wall_s=%.1f", time.time() - t0)
+    if failed:
+        log.error("failed suites: %s", ",".join(failed))
+        sys.exit(1)
 
 
 if __name__ == "__main__":

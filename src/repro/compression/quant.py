@@ -38,7 +38,9 @@ def dither_u01(seed, idx):
     h = h ^ (h >> 15)
     h = h * jnp.uint32(0x846CA68B)
     h = h ^ (h >> 16)
-    return h.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    # top 24 bits through int32: exact in float32, so u < 1 always, and the
+    # chip's compiler has no uint32 -> float32 cast inside a Pallas kernel
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32) * jnp.float32(2.0 ** -24)
 
 
 def quant_levels(b):

@@ -1,7 +1,7 @@
 """ResNet-9 on CIFAR-10 — the paper's own image-classification model (§VI).
 
 Nine conv layers + BN + ReLU, two residual blocks, global pooling, FC head;
-6,568,650 parameters at full width. ``d_model`` doubles as the base channel
+6,573,130 parameters at full width (``model.num_params()``). ``d_model`` doubles as the base channel
 width (64 at full size).
 """
 from repro.configs.base import ModelConfig, register

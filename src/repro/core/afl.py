@@ -17,6 +17,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.compression.base import Compressor, CompressorState
 from repro.core import mads as M
@@ -132,7 +133,8 @@ class Policy:
         return k, p, energy
 
 
-def compress_uploads(comp: Compressor, g_n, e_n, ckey, budget_bits, n: int):
+def compress_uploads(comp: Compressor, g_n, e_n, ckey, budget_bits, n: int,
+                     mesh=None):
     """One codec pass over the federation — shared by BOTH engines.
 
     The single-host ``afl_round`` below and the pjit distributed step
@@ -141,13 +143,25 @@ def compress_uploads(comp: Compressor, g_n, e_n, ckey, budget_bits, n: int):
     identical — which is what makes their uploads bit-identical (the
     parity suite in tests/test_distributed_compression.py pins this).
 
+    ``mesh``: the distributed step's mesh when the client axis is sharded
+    over its ``pod``/``data`` axes.  GSPMD cannot partition a Pallas
+    kernel, so the pass then runs under ``shard_map``: each device
+    compresses its own clients, whose tensors are whole on it.
+
     Returns ``(upload, e_after, cstats, ckey)``: the dense dequantised
     payloads, the error-feedback memories, the per-device ``{"k", "bits",
     "b"}`` stats, and the advanced PRNG carry.
     """
     ckey, sub = jax.random.split(ckey)
     dev_keys = jax.random.split(sub, n)
-    upload, cstate, cstats = jax.vmap(comp.compress)(
+    codec = jax.vmap(comp.compress)
+    if mesh is not None:
+        spec = P(tuple(a for a in ("pod", "data") if a in mesh.axis_names))
+        # check_vma=False: the kernels' out_shape carries no varying-axes
+        # type; every output varies over the client axes, as out_specs say
+        codec = jax.shard_map(codec, mesh=mesh, in_specs=spec,
+                              out_specs=spec, check_vma=False)
+    upload, cstate, cstats = codec(
         g_n, budget_bits, CompressorState(error=e_n, key=dev_keys)
     )
     return upload, cstate.error, cstats, ckey

@@ -172,7 +172,7 @@ def _split_clients(batch, n: int):
 
 def make_afl_train_step(model, cfg, dcfg: DistConfig, controller: MadsController,
                         compressor: Compressor | None = None,
-                        telemetry=None, staleness=None):
+                        telemetry=None, staleness=None, mesh: Mesh | None = None):
     """Builds the jittable distributed AFL round.
 
     ``compressor``: optional ``repro.compression`` codec; when given, the
@@ -193,6 +193,11 @@ def make_afl_train_step(model, cfg, dcfg: DistConfig, controller: MadsController
     ``alpha * s(delta_tau)`` aggregation discount applied to the client-
     axis contraction, identical to the single-host ``afl_round`` mixing
     (None or the identity family keeps the paper's constant rule).
+
+    ``mesh``: the mesh whose ``pod``/``data`` axes carry the client axis;
+    the codec pass then runs per device under ``shard_map``, because GSPMD
+    cannot partition the Pallas codec kernels (``compress_uploads``).
+    Parameter dims must be whole within a client on that path.
     """
     n = dcfg.num_clients
     eta = dcfg.learning_rate
@@ -230,7 +235,8 @@ def make_afl_train_step(model, cfg, dcfg: DistConfig, controller: MadsController
                               controller.noise_w_hz)
             budget_bits = tau * rate * okf
             upload, e_after, cstats, ckey = compress_uploads(
-                compressor, g_new, state.e_n, state.ckey, budget_bits, n
+                compressor, g_new, state.e_n, state.ckey, budget_bits, n,
+                mesh=mesh,
             )
             k_actual = cstats["k"]
             bits = cstats["bits"] * okf
@@ -423,7 +429,7 @@ def make_afl_train_system(model, cfg, mesh: Mesh, dcfg: DistConfig | None = None
     controller = controller or MadsController(s=model.num_params())
     step = make_afl_train_step(model, cfg, dcfg, controller,
                                compressor=compressor, telemetry=telemetry,
-                               staleness=staleness)
+                               staleness=staleness, mesh=mesh)
     st_sh = state_shardings(model, mesh, dcfg, rules)
     rep = NamedSharding(mesh, P())
     return {

@@ -22,6 +22,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+
 
 def bits_for_k(k, s: int, u: int = 32):
     """Upload payload in bits for k selected of s parameters (paper §III-D)."""
@@ -64,15 +66,9 @@ def sparsify_topk(x: jax.Array, k, *, method: str = "exact", sample: int = 65536
     """
     x_abs = jnp.abs(x.astype(jnp.float32))
     t = threshold_for_k(x_abs, k, method=method, sample=sample)
-    if jax.default_backend() == "tpu" and x.ndim == 1:
-        # fused single-pass kernel (repro/kernels/sparsify_ef.py)
-        from repro.kernels.sparsify_ef import sparsify_ef as _kernel
-
-        return _kernel(x, t, interpret=False)
-    mask = x_abs >= t
-    upload = jnp.where(mask, x, jnp.zeros_like(x))
-    error = jnp.where(mask, jnp.zeros_like(x), x)
-    return upload, error, jnp.sum(mask).astype(jnp.float32)
+    # the fused single-pass op; kernels/ops.py picks kernel or oracle
+    upload, error, count = ops.sparsify_ef(x.reshape(-1), t)
+    return upload.reshape(x.shape), error.reshape(x.shape), count
 
 
 def quantize_values(x, bits: int):

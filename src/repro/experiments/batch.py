@@ -31,14 +31,26 @@ log = get_logger("repro.batch")
 
 @lru_cache(maxsize=16)
 def _compiled_vrun(model, cfg, fl, policy, rounds: int, eval_every: int,
-                   sampler, telemetry=None):
-    """vmapped whole-run program, cached per (model, engine-flags) group."""
+                   sampler, telemetry=None, mesh=None):
+    """vmapped whole-run program, cached per (model, engine-flags) group.
+
+    With a seed ``mesh`` each device runs its own seeds under
+    ``shard_map``: seeds never communicate, and GSPMD cannot partition
+    the Pallas codec kernels inside the run."""
     run = make_run_fn(model, cfg, fl, policy, rounds=rounds,
                       eval_every=eval_every, sampler=sampler,
                       telemetry=telemetry)
     # batched: state0, zeta, tau, h2, budgets, sample_ctx, telemetry state,
     # heterogeneity aux masks; shared: eval_batch
-    return jax.jit(jax.vmap(run, in_axes=(0, 0, 0, 0, 0, None, 0, 0, 0)))
+    vrun = jax.vmap(run, in_axes=(0, 0, 0, 0, 0, None, 0, 0, 0))
+    if mesh is not None:
+        seed = P(mesh.axis_names[0])
+        # check_vma=False: the kernels' out_shape carries no varying-axes
+        # type; every output varies over the seed axis, as out_specs say
+        vrun = jax.shard_map(vrun, mesh=mesh,
+                             in_specs=(seed,) * 5 + (P(),) + (seed,) * 3,
+                             out_specs=seed, check_vma=False)
+    return jax.jit(vrun)
 
 
 @lru_cache(maxsize=64)
@@ -146,7 +158,7 @@ def run_seed_batch(
         eval_b = jax.device_put(eval_b, NamedSharding(mesh, P()))
 
     vrun = _compiled_vrun(model, cfg, efl, epolicy, rounds, eval_every,
-                          shard.traced_batch, telemetry)
+                          shard.traced_batch, telemetry, mesh)
     states, hist_dev, tstates = vrun(state0, zeta, tau, h2, budgets, eval_b,
                                      sample_keys, tstate0, het)
 

@@ -1,10 +1,12 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
 ``impl`` selection:
-  "auto"              Pallas compiled on TPU, pure-jnp reference elsewhere
-                      (this container is CPU, so production dispatch falls
-                      back to the oracle — the kernels are validated in
-                      interpret mode by the test suite).
+  "auto"              Pallas compiled on TPU, pure-jnp reference elsewhere.
+                      A CPU run (the tests) takes the oracle; the kernels
+                      are checked there in interpret mode.  The choice reads
+                      ``jax.default_backend()`` while tracing, so a program
+                      traced in a CPU process holds the oracle even when it
+                      is compiled ahead of time for a TPU.
   "pallas"            pl.pallas_call compiled (TPU).
   "pallas_interpret"  kernel body executed in Python on CPU (tests).
   "ref"               pure-jnp oracle.

@@ -9,16 +9,10 @@ parallelism when serving.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.4.38; older versions default to Auto axes anyway
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -39,22 +33,9 @@ def force_host_device_count(n: int) -> None:
     """Simulate ``n`` host devices (CI meshes, parity suites, --mesh flags).
 
     Must run before the jax backend initialises (device count is fixed at
-    first backend use).  Prefers the ``jax_num_cpu_devices`` config of
-    newer jax; on older versions falls back to the
-    ``--xla_force_host_platform_device_count`` XLA flag, which the lazily
-    initialised backend still honours post-import.
+    first backend use); ``jax_num_cpu_devices`` raises after that.
     """
-    import os
-
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-        return
-    except Exception:  # pragma: no cover - depends on installed jax
-        pass
-    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-             if "host_platform_device_count" not in f]
-    flags.append(f"--xla_force_host_platform_device_count={n}")
-    os.environ["XLA_FLAGS"] = " ".join(flags)
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def make_client_mesh(num_clients: int):

@@ -172,6 +172,9 @@ def run_soak(*, uploads: int = 10_000, batch: int = 256, s: int = 4096,
 
 
 def main(argv=None) -> None:
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--uploads", type=int, default=10_000)
     ap.add_argument("--batch", type=int, default=256)

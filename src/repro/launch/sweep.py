@@ -41,6 +41,7 @@ from repro.experiments import (
     ResultsStore,
     run_seed_batch,
 )
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_seed_mesh
 from repro.launch.train import build_device_data
 from repro.models.registry import build_model
@@ -133,6 +134,7 @@ CODEC_POLICIES = {
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="resnet9-cifar10")
     ap.add_argument("--policies", default="mads,afl-spar,afl",

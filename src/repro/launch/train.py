@@ -32,6 +32,7 @@ from repro.data import (
     SyntheticTrajectories,
     dirichlet_partition,
 )
+from repro.launch.cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.telemetry import (
     JsonlSink,
@@ -84,6 +85,7 @@ def build_federation(cfg, fl, *, train_n=2000, eval_n=512, seq_len=64, seed=0):
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="resnet9-cifar10")
     ap.add_argument("--policy", default="mads", choices=sorted(BL.ALL))

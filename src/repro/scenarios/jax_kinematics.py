@@ -186,8 +186,10 @@ def _gm_positions(key, steps: int, dt: float, n: int, area: float,
     return _reflect(x0[None] + disp, area)
 
 
-_DIRS = jnp.asarray([[1.0, 0.0], [0.0, 1.0], [-1.0, 0.0], [0.0, -1.0]],
-                    jnp.float32)
+# host constant: a jnp array here would start the JAX backend at import,
+# before an entry point could set the host device count
+_DIRS = np.asarray([[1.0, 0.0], [0.0, 1.0], [-1.0, 0.0], [0.0, -1.0]],
+                   np.float32)
 
 
 def _manhattan_positions(key, steps: int, dt: float, n: int, area: float,
@@ -212,7 +214,7 @@ def _manhattan_positions(key, steps: int, dt: float, n: int, area: float,
     start = (jax.random.randint(kx, (n, 2), 0, grid_n + 1)
              .astype(jnp.float32) * block)
     nodes = start[:, None, :] + block * jnp.concatenate(
-        [jnp.zeros((n, 1, 2), jnp.float32), jnp.cumsum(_DIRS[head], axis=1)],
+        [jnp.zeros((n, 1, 2), jnp.float32), jnp.cumsum(jnp.asarray(_DIRS)[head], axis=1)],
         axis=1)
     # reflection folds lattice points onto lattice points (block | area)
     nodes = _reflect(nodes, a)

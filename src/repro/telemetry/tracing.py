@@ -56,11 +56,8 @@ class PhaseTracer:
         as ``parent`` and its ``depth``, surfaced by ``events()``."""
         ann = None
         if self.profile_dir is not None:
-            try:
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:  # pragma: no cover - profiler backend-dependent
-                ann = None
+            ann = jax.profiler.TraceAnnotation(name)
+            ann.__enter__()
         parent = self._stack[-1] if self._stack else None
         depth = len(self._stack)
         self._stack.append(name)
@@ -83,24 +80,23 @@ class PhaseTracer:
     @staticmethod
     def fence(x):
         """Block until ``x``'s arrays are computed (no-op on host data) —
-        call before leaving a span so its wall time covers the work."""
-        try:
-            jax.block_until_ready(x)
-        except Exception:  # non-array pytrees / already-deleted buffers
-            pass
+        call before leaving a span so its wall time covers the work.  A
+        device error surfaces here and propagates."""
+        jax.block_until_ready(x)
         return x
 
     # -- profiler bracket ----------------------------------------------------
 
     def start(self) -> None:
-        """Begin a device trace under ``profile_dir`` (no-op without)."""
+        """Begin a device trace under ``profile_dir`` (no-op without).
+
+        A trace that was asked for and cannot start raises: the run does not
+        go on silently untraced.
+        """
         if self.profile_dir is None or self._tracing:
             return
-        try:
-            jax.profiler.start_trace(self.profile_dir)
-            self._tracing = True
-        except Exception:  # pragma: no cover - profiler backend-dependent
-            self.profile_dir = None
+        jax.profiler.start_trace(self.profile_dir)
+        self._tracing = True
 
     def stop(self) -> None:
         if self._tracing:

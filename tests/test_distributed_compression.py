@@ -185,7 +185,6 @@ from repro.launch.mesh import force_host_device_count
 force_host_device_count(2)
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.compression.base import strict_threshold
 from repro.compression.quant import tree_amax
@@ -213,7 +212,7 @@ def body(xl):
                          axis="data", s=x.size)
     return t[None], tree_amax(xl, axis="data")[None]
 
-ts, ams = jax.jit(shard_map(
+ts, ams = jax.jit(jax.shard_map(
     body, mesh=mesh1d, in_specs=P("data"), out_specs=P("data")
 ))(jnp.asarray(x))
 ts, ams = np.asarray(ts), np.asarray(ams)
@@ -244,8 +243,11 @@ def run(policy_name, fl, sharded):
     dcfg = DistConfig(num_clients=fl.num_devices, rounds=ROUNDS,
                       learning_rate=fl.learning_rate, state_dtype="float32",
                       upload_dtype="float32")
+    # sharded: the codec pass runs per device under shard_map (as it must
+    # for the Pallas kernels on a TPU mesh)
     step = jax.jit(make_afl_train_step(model, cfg, dcfg, policy.controller,
-                                       compressor=policy.compressor))
+                                       compressor=policy.compressor,
+                                       mesh=mesh if sharded else None))
     state = init_state(model, dcfg, jax.random.key(0))
     if sharded:  # commit the client axis to the 2-device data axis
         state = jax.device_put(state, client_state_shardings(state, mesh))

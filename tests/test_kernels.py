@@ -68,6 +68,29 @@ def test_sparsify_quantize_ef_matches_ref(n, dtype):
         assert float(c) == float(cr), (n, t)
 
 
+def test_kernels_vmapped_over_devices_match_ref():
+    """The codec pass vmaps the kernels over devices, each with its own
+    threshold/step/seed: the batched kernels still match the oracle."""
+    x = jnp.asarray(RNG.normal(0, 1, (3, 300001)), jnp.float32)
+    t = jnp.asarray([0.0, 0.7, np.inf], jnp.float32)
+    step = jnp.asarray([0.01, 0.02, 0.05], jnp.float32)
+    levels = jnp.full((3,), 127.0, jnp.float32)
+    seed = jnp.asarray([1, 2, 3], jnp.int32)
+    u, e, c = jax.vmap(lambda *a: sparsify_quantize_ef(*a, 5))(
+        x, t, step, levels, seed)
+    ur, er, cr = jax.vmap(
+        lambda *a: sparsify_quantize_ef_ref(*a, base=5))(
+        x, t, step, levels, seed)
+    np.testing.assert_array_equal(np.asarray(u), np.asarray(ur))
+    np.testing.assert_allclose(np.asarray(e), np.asarray(er), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(cr))
+    u, e, c = jax.vmap(sparsify_ef)(x, t)
+    ur, er, cr = jax.vmap(sparsify_ef_ref)(x, t)
+    np.testing.assert_array_equal(np.asarray(u), np.asarray(ur))
+    np.testing.assert_array_equal(np.asarray(e), np.asarray(er))
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(cr))
+
+
 def test_sparsify_quantize_ef_semantics():
     """Upload values sit on the step grid; EF absorbs the quant residual."""
     x = jnp.asarray(RNG.normal(0, 1, 4096), jnp.float32)
