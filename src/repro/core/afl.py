@@ -23,6 +23,7 @@ from repro.compression.base import Compressor, CompressorState
 from repro.core import mads as M
 from repro.core import sparsify as SP
 from repro.core.mads import MadsController
+from repro.telemetry.tracing import phase
 
 
 class AflState(NamedTuple):
@@ -206,94 +207,101 @@ def afl_round(state: AflState, batch, zeta, tau, h2, energy_budget,
     n = fl.num_devices
     eta = fl.learning_rate
     ctl = policy.controller or MadsController(s=model.num_params())
-    r = state.rnd + 1
-    theta = (r - state.kappa).astype(jnp.float32)
+    # the round index and the staleness the upload decision reads
+    with phase("select"):
+        r = state.rnd + 1
+        theta = (r - state.kappa).astype(jnp.float32)
 
     # --- local stochastic gradients (all devices, vmapped) -----------------
-    grad_fn = jax.vmap(jax.grad(lambda p, b: model.loss_fn(p, cfg, b)))
-    grads = grad_fn(state.w_n, batch)
-    if not policy.train_every_round:
-        grads = jax.tree.map(lambda g: g * _bcast_to(zeta.astype(g.dtype), g), grads)
+    with phase("grads"):
+        grad_fn = jax.vmap(jax.grad(lambda p, b: model.loss_fn(p, cfg, b)))
+        grads = grad_fn(state.w_n, batch)
+        if not policy.train_every_round:
+            grads = jax.tree.map(lambda g: g * _bcast_to(zeta.astype(g.dtype), g), grads)
 
-    g_new = jax.tree.map(lambda g, d: g + eta * d.astype(g.dtype), state.g_n, grads)
+        g_new = jax.tree.map(lambda g, d: g + eta * d.astype(g.dtype), state.g_n, grads)
 
     # --- upload decision (MADS or baseline policy) --------------------------
-    x = jax.tree.map(jnp.add, state.e_n, g_new)
-    x_norm2 = sum(
-        jnp.sum(jnp.square(l.astype(jnp.float32)), axis=tuple(range(1, l.ndim)))
-        for l in jax.tree.leaves(x)
-    )
-    zf = zeta.astype(jnp.float32)
-    k, p, energy = policy.select(ctl, zf, theta, x_norm2, state.q, tau, h2)
-    ok = zf > 0
-    if policy.energy_capped:
-        ok = ok & (state.energy + energy <= energy_budget)
-    k = k * ok
-    energy = energy * ok
-    okf = ok.astype(jnp.float32)
+    with phase("select"):
+        x = jax.tree.map(jnp.add, state.e_n, g_new)
+        x_norm2 = sum(
+            jnp.sum(jnp.square(l.astype(jnp.float32)), axis=tuple(range(1, l.ndim)))
+            for l in jax.tree.leaves(x)
+        )
+        zf = zeta.astype(jnp.float32)
+        k, p, energy = policy.select(ctl, zf, theta, x_norm2, state.q, tau, h2)
+        ok = zf > 0
+        if policy.energy_capped:
+            ok = ok & (state.energy + energy <= energy_budget)
+        k = k * ok
+        energy = energy * ok
+        okf = ok.astype(jnp.float32)
 
     # --- compression with error feedback -----------------------------------
-    if policy.compressor is not None:
-        # codec path: the budget is the realised contact capacity tau*A(p)
-        # (Proposition 1's left-hand side); the codec decides how to spend
-        # it (k, b, or both) and returns the EF residual as its state
-        rate = M.rate_bps(p, h2, ctl.bandwidth, ctl.noise_w_hz)
-        budget_bits = tau * rate * okf
-        upload, e_after, cstats, ckey = compress_uploads(
-            policy.compressor, g_new, state.e_n, state.ckey, budget_bits, n
-        )
-        k_actual = cstats["k"]
-        bits = cstats["bits"] * okf
-        b_used = cstats["b"] * okf
-    else:
-        # seed path: top-k at fixed ctl.u-bit values (paper §III-D)
-        ckey = state.ckey
-        upload, e_after, k_actual = jax.vmap(
-            lambda t, kk: SP.sparsify_tree(t, kk, method=fl.sparsifier, sample=fl.sample_size)
-        )(x, k)
-        if ctl.u < 32:  # quantized wire format: EF absorbs the residual too
-            upload_q = jax.vmap(lambda t: SP.quantize_values(t, ctl.u))(upload)
-            e_after = jax.tree.map(lambda e, u, uq: e + (u - uq), e_after, upload, upload_q)
-            upload = upload_q
-        bits = SP.bits_for_k(k_actual, ctl.s, ctl.u) * okf
-        b_used = jnp.full_like(k_actual, float(ctl.u)) * okf
-    if not policy.error_feedback:
-        e_after = jax.tree.map(jnp.zeros_like, e_after)
+    with phase("compress"):
+        if policy.compressor is not None:
+            # codec path: the budget is the realised contact capacity tau*A(p)
+            # (Proposition 1's left-hand side); the codec decides how to spend
+            # it (k, b, or both) and returns the EF residual as its state
+            rate = M.rate_bps(p, h2, ctl.bandwidth, ctl.noise_w_hz)
+            budget_bits = tau * rate * okf
+            upload, e_after, cstats, ckey = compress_uploads(
+                policy.compressor, g_new, state.e_n, state.ckey, budget_bits, n
+            )
+            k_actual = cstats["k"]
+            bits = cstats["bits"] * okf
+            b_used = cstats["b"] * okf
+        else:
+            # seed path: top-k at fixed ctl.u-bit values (paper §III-D)
+            ckey = state.ckey
+            upload, e_after, k_actual = jax.vmap(
+                lambda t, kk: SP.sparsify_tree(t, kk, method=fl.sparsifier, sample=fl.sample_size)
+            )(x, k)
+            if ctl.u < 32:  # quantized wire format: EF absorbs the residual too
+                upload_q = jax.vmap(lambda t: SP.quantize_values(t, ctl.u))(upload)
+                e_after = jax.tree.map(lambda e, u, uq: e + (u - uq), e_after, upload, upload_q)
+                upload = upload_q
+            bits = SP.bits_for_k(k_actual, ctl.s, ctl.u) * okf
+            b_used = jnp.full_like(k_actual, float(ctl.u)) * okf
+        if not policy.error_feedback:
+            e_after = jax.tree.map(jnp.zeros_like, e_after)
 
     # --- MES aggregation: w <- w - (1/N) sum a s(theta) zeta S(x_n) ---------
     # mixing weight: the FedAsync alpha * s(delta_tau) staleness discount;
     # the default family is the identity (compile-time branch), keeping the
     # paper's constant rule — and the serve-path fused ingest op applies
     # the SAME weights, which is what makes the two paths bit-comparable
-    mix = okf if policy.staleness.is_identity \
-        else okf * policy.staleness.weight(theta)
-    w_new = jax.tree.map(
-        lambda w, up: (
-            w - (jnp.tensordot(mix, up.astype(jnp.float32), axes=(0, 0)) / n).astype(w.dtype)
-        ),
-        state.w,
-        upload,
-    )
+    with phase("aggregate"):
+        mix = okf if policy.staleness.is_identity \
+            else okf * policy.staleness.weight(theta)
+        w_new = jax.tree.map(
+            lambda w, up: (
+                w - (jnp.tensordot(mix, up.astype(jnp.float32), axes=(0, 0)) / n).astype(w.dtype)
+            ),
+            state.w,
+            upload,
+        )
 
     # --- device-side state transitions --------------------------------------
-    w_local = (
-        jax.tree.map(lambda wn, d: wn - eta * d.astype(wn.dtype), state.w_n, grads)
-        if policy.local_updates
-        else state.w_n
-    )
-    w_bcast = jax.tree.map(lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), w_new)
-    w_n_new = _select(okf, w_bcast, w_local)
-    e_n_new = _select(okf, e_after, state.e_n)
-    g_n_new = _select(okf, jax.tree.map(jnp.zeros_like, g_new), g_new)
-    kappa_new = jnp.where(ok, r, state.kappa)
-    q_new = ctl.queue_update(state.q, energy, energy_budget, fl.rounds)
+    with phase("state"):
+        w_local = (
+            jax.tree.map(lambda wn, d: wn - eta * d.astype(wn.dtype), state.w_n, grads)
+            if policy.local_updates
+            else state.w_n
+        )
+        w_bcast = jax.tree.map(lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), w_new)
+        w_n_new = _select(okf, w_bcast, w_local)
+        e_n_new = _select(okf, e_after, state.e_n)
+        g_n_new = _select(okf, jax.tree.map(jnp.zeros_like, g_new), g_new)
+        kappa_new = jnp.where(ok, r, state.kappa)
+        q_new = ctl.queue_update(state.q, energy, energy_budget, fl.rounds)
 
-    # per-device EF-memory squared norm (Lemma 4's E||e_n||^2, observable):
-    # same leaf-order reduction as x_norm2 so engines agree bit-for-bit
-    e_norm2 = sum(
-        jnp.sum(jnp.square(l.astype(jnp.float32)), axis=tuple(range(1, l.ndim)))
-        for l in jax.tree.leaves(e_n_new)
-    )
+        # per-device EF-memory squared norm (Lemma 4's E||e_n||^2, observable):
+        # same leaf-order reduction as x_norm2 so engines agree bit-for-bit
+        e_norm2 = sum(
+            jnp.sum(jnp.square(l.astype(jnp.float32)), axis=tuple(range(1, l.ndim)))
+            for l in jax.tree.leaves(e_n_new)
+        )
     metrics = {
         "k": k_actual * okf,
         "k_target": k,

@@ -43,6 +43,7 @@ from repro.core import sparsify as SP
 from repro.core.afl import compress_uploads
 from repro.core.mads import MadsController
 from repro.sharding import rules as R
+from repro.telemetry.tracing import phase
 
 
 class DistAflState(NamedTuple):
@@ -205,91 +206,99 @@ def make_afl_train_step(model, cfg, dcfg: DistConfig, controller: MadsController
 
     def step(state: DistAflState, batch, zeta, tau, h2, budgets,
              tstate=None):
-        r = state.rnd + 1
-        theta = (r - state.kappa).astype(jnp.float32)
+        # the round index and the staleness the upload decision reads
+        with phase("select"):
+            r = state.rnd + 1
+            theta = (r - state.kappa).astype(jnp.float32)
 
-        cl_batch = _split_clients(batch, n)
-        grad_fn = jax.vmap(jax.grad(lambda p, b: model.loss_fn(p, cfg, b)))
-        grads = grad_fn(state.w_n, cl_batch)
+        with phase("grads"):
+            cl_batch = _split_clients(batch, n)
+            grad_fn = jax.vmap(jax.grad(lambda p, b: model.loss_fn(p, cfg, b)))
+            grads = grad_fn(state.w_n, cl_batch)
 
-        at = jnp.dtype(dcfg.accum_dtype)
-        g_new = jax.tree.map(
-            lambda g, d: (g.astype(at) + eta * d.astype(at)).astype(g.dtype),
-            state.g_n, grads,
-        )
-        x = jax.tree.map(lambda e, g: e + g, state.e_n, g_new)
-        x_norm2 = sum(
-            jnp.sum(jnp.square(l.astype(jnp.float32)), axis=tuple(range(1, l.ndim)))
-            for l in jax.tree.leaves(x)
-        )
-
-        zf = zeta.astype(jnp.float32)
-        k, p, energy = controller.select(zf, theta, x_norm2, state.q, tau, h2)
-        ok = zf > 0
-        okf = ok.astype(jnp.float32)
-        k = k * okf
-        energy = energy * okf
-
-        if compressor is not None:
-            rate = M.rate_bps(p, h2, controller.bandwidth,
-                              controller.noise_w_hz)
-            budget_bits = tau * rate * okf
-            upload, e_after, cstats, ckey = compress_uploads(
-                compressor, g_new, state.e_n, state.ckey, budget_bits, n,
-                mesh=mesh,
+            at = jnp.dtype(dcfg.accum_dtype)
+            g_new = jax.tree.map(
+                lambda g, d: (g.astype(at) + eta * d.astype(at)).astype(g.dtype),
+                state.g_n, grads,
             )
-            k_actual = cstats["k"]
-            bits = cstats["bits"] * okf
-            b_used = cstats["b"] * okf
-        else:
-            ckey = state.ckey
-            upload, e_after, k_actual = jax.vmap(
-                lambda t, kk: SP.sparsify_tree(t, kk, method="sampled",
-                                               sample=dcfg.sample_size)
-            )(x, k)
-            bits = SP.bits_for_k(k_actual, controller.s, controller.u) * okf
-            b_used = jnp.full_like(k_actual, float(controller.u)) * okf
+
+        with phase("select"):
+            x = jax.tree.map(lambda e, g: e + g, state.e_n, g_new)
+            x_norm2 = sum(
+                jnp.sum(jnp.square(l.astype(jnp.float32)), axis=tuple(range(1, l.ndim)))
+                for l in jax.tree.leaves(x)
+            )
+
+            zf = zeta.astype(jnp.float32)
+            k, p, energy = controller.select(zf, theta, x_norm2, state.q, tau, h2)
+            ok = zf > 0
+            okf = ok.astype(jnp.float32)
+            k = k * okf
+            energy = energy * okf
+
+        with phase("compress"):
+            if compressor is not None:
+                rate = M.rate_bps(p, h2, controller.bandwidth,
+                                  controller.noise_w_hz)
+                budget_bits = tau * rate * okf
+                upload, e_after, cstats, ckey = compress_uploads(
+                    compressor, g_new, state.e_n, state.ckey, budget_bits, n,
+                    mesh=mesh,
+                )
+                k_actual = cstats["k"]
+                bits = cstats["bits"] * okf
+                b_used = cstats["b"] * okf
+            else:
+                ckey = state.ckey
+                upload, e_after, k_actual = jax.vmap(
+                    lambda t, kk: SP.sparsify_tree(t, kk, method="sampled",
+                                                   sample=dcfg.sample_size)
+                )(x, k)
+                bits = SP.bits_for_k(k_actual, controller.s, controller.u) * okf
+                b_used = jnp.full_like(k_actual, float(controller.u)) * okf
 
         # MES aggregation: contract the client axis (hierarchical all-reduce)
         # with the optional alpha * s(delta_tau) staleness discount — the
         # same mixing weights as afl_round and the serve-path fused ingest
-        udt = jnp.dtype(dcfg.upload_dtype)
-        mix = okf if sw is None else okf * sw.weight(theta)
-        w_new = jax.tree.map(
-            lambda w, up: (
-                w.astype(udt)
-                - jnp.tensordot(mix.astype(udt), up.astype(udt), axes=(0, 0)) / n
-            ).astype(w.dtype),
-            state.w, upload,
-        )
+        with phase("aggregate"):
+            udt = jnp.dtype(dcfg.upload_dtype)
+            mix = okf if sw is None else okf * sw.weight(theta)
+            w_new = jax.tree.map(
+                lambda w, up: (
+                    w.astype(udt)
+                    - jnp.tensordot(mix.astype(udt), up.astype(udt), axes=(0, 0)) / n
+                ).astype(w.dtype),
+                state.w, upload,
+            )
 
-        bcast = lambda l: jnp.broadcast_to(l[None], (n,) + l.shape)
-        cond = lambda c, leaf: c.reshape(c.shape + (1,) * (leaf.ndim - 1))
-        sdt = jnp.dtype(dcfg.state_dtype)
-        w_n_new = jax.tree.map(
-            lambda wn, wg, d: jnp.where(
-                cond(ok, wn), bcast(wg).astype(sdt),
-                (wn.astype(at) - eta * d.astype(at)).astype(sdt),
-            ),
-            state.w_n, w_new, grads,
-        )
-        e_n_new = jax.tree.map(
-            lambda new, old: jnp.where(cond(ok, new), new.astype(sdt), old),
-            e_after, state.e_n,
-        )
-        g_n_new = jax.tree.map(
-            lambda g: jnp.where(cond(ok, g), jnp.zeros_like(g), g), g_new
-        )
-        kappa_new = jnp.where(ok, r, state.kappa)
-        q_new = controller.queue_update(state.q, energy, budgets, dcfg.rounds)
+        with phase("state"):
+            bcast = lambda l: jnp.broadcast_to(l[None], (n,) + l.shape)
+            cond = lambda c, leaf: c.reshape(c.shape + (1,) * (leaf.ndim - 1))
+            sdt = jnp.dtype(dcfg.state_dtype)
+            w_n_new = jax.tree.map(
+                lambda wn, wg, d: jnp.where(
+                    cond(ok, wn), bcast(wg).astype(sdt),
+                    (wn.astype(at) - eta * d.astype(at)).astype(sdt),
+                ),
+                state.w_n, w_new, grads,
+            )
+            e_n_new = jax.tree.map(
+                lambda new, old: jnp.where(cond(ok, new), new.astype(sdt), old),
+                e_after, state.e_n,
+            )
+            g_n_new = jax.tree.map(
+                lambda g: jnp.where(cond(ok, g), jnp.zeros_like(g), g), g_new
+            )
+            kappa_new = jnp.where(ok, r, state.kappa)
+            q_new = controller.queue_update(state.q, energy, budgets, dcfg.rounds)
 
-        # same leaf-order reduction as the single-host afl_round so the
-        # per-device table / probe accumulators stay engine-comparable
-        e_norm2 = sum(
-            jnp.sum(jnp.square(l.astype(jnp.float32)),
-                    axis=tuple(range(1, l.ndim)))
-            for l in jax.tree.leaves(e_n_new)
-        )
+            # same leaf-order reduction as the single-host afl_round so the
+            # per-device table / probe accumulators stay engine-comparable
+            e_norm2 = sum(
+                jnp.sum(jnp.square(l.astype(jnp.float32)),
+                        axis=tuple(range(1, l.ndim)))
+                for l in jax.tree.leaves(e_n_new)
+            )
         metrics = {
             "k": k_actual * okf,
             "success": (k_actual > 0).astype(jnp.float32) * okf,

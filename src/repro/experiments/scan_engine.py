@@ -39,6 +39,7 @@ from repro.core.runner import (
     sample_budgets,
 )
 from repro.telemetry import HIST_KEYS, record_het, record_round
+from repro.telemetry.tracing import phase
 from repro.utils import get_logger
 
 log = get_logger("repro.scan_engine")
@@ -161,7 +162,8 @@ def make_run_fn(model, cfg, fl, policy, *, rounds: int, eval_every: int,
         def body(carry, xs):
             state, tot, ts = carry
             r, zeta_r, tau_r, h2_r, het_r = xs
-            batch = sampler(sample_ctx, r)
+            with phase("sample"):
+                batch = sampler(sample_ctx, r)
             state, m = afl_round(
                 state, batch, zeta_r, tau_r, h2_r, budgets,
                 model=model, cfg=cfg, fl=fl, policy=policy,
@@ -191,7 +193,8 @@ def make_run_fn(model, cfg, fl, policy, *, rounds: int, eval_every: int,
             )
             (state, tot, ts), _ = jax.lax.scan(body, (state, tot, ts), xs)
             up = jnp.maximum(tot["uploads"], 1.0)
-            hist["eval"].append(eval_fn(state.w, eval_batch))
+            with phase("eval"):
+                hist["eval"].append(eval_fn(state.w, eval_batch))
             hist["uploads"].append(tot["uploads"])
             hist["k_mean"].append(tot["k"] / up)
             hist["energy"].append(jnp.sum(state.energy))

@@ -22,9 +22,15 @@ def use_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
     no other directory is set here.  Otherwise the cache lives at
     ``<checkout>/.jax_cache``.
+
+    The cache key includes the programs' metadata.  JAX's default key
+    strips it, so an executable compiled from other code that lowers to
+    the same instructions (other ``afl.*`` scopes, or none) would be
+    loaded, and a device trace would name its ops by that code's scopes.
     """
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get(ENV)
     if not path:
         path = str(DEFAULT_DIR)

@@ -57,6 +57,7 @@ from repro.telemetry import (
     report_from_config,
     to_jsonable,
 )
+from repro.telemetry.tracing import compiles
 from repro.utils import get_logger
 
 log = get_logger("repro.sweep")
@@ -274,8 +275,11 @@ def main() -> None:
         table = run_sweep(grid, store, model, cfg, shard, ev, mesh=mesh,
                           telemetry=telemetry, tracer=tracer, sink=sink)
         sink.extend(tracer.events())
-        if sink.events:  # a fully-resumed sweep must not blank the
-            sink.flush()  # previous invocation's telemetry artifact
+        # a fully-resumed sweep must not blank the previous invocation's
+        # telemetry artifact
+        if sink.events:
+            sink.emit({"kind": "compiles", **compiles.totals()})
+            sink.flush()
     finally:
         tracer.stop()
     print(table)

@@ -41,6 +41,7 @@ from repro.telemetry import (
     report_from_config,
     to_jsonable,
 )
+from repro.telemetry.tracing import compiles
 from repro.utils import get_logger
 
 log = get_logger("repro.train")
@@ -199,6 +200,7 @@ def main() -> None:
     telemetry = resolve_telemetry(fl, None, s=model.num_params())
     with JsonlSink(os.path.join(args.workdir, "telemetry.jsonl")) as sink:
         sink.extend(tracer.events())
+        sink.emit({"kind": "compiles", **compiles.totals()})
         if res.telemetry is not None:
             sink.emit({"kind": "metrics", **to_jsonable(res.telemetry)})
             if (isinstance(telemetry, TelemetrySuite)

@@ -13,10 +13,21 @@ Optional profiler hooks: constructing the tracer with ``profile_dir``
 ``start_trace``/``stop_trace`` so spans line up with the device timeline
 in TensorBoard/Perfetto.  Without ``profile_dir`` the tracer costs two
 ``perf_counter`` calls and a list append per span.
+
+Device-side phases: ``phase(name)`` opens the ``jax.named_scope``
+``afl.<name>`` around a block of the round (``PHASES``).  A scope only
+names the HLO instructions it lowers to (their ``op_name`` metadata), so
+the compiled program is the same with or without it; ``op_phases`` maps
+the instructions of a compiled program's text to their phase, which is
+how a device trace's ops are read by phase.
+
+``compiles`` counts, for the whole process, the programs JAX traces,
+lowers and compiles and the seconds it spends doing so (``CompileCounter``).
 """
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from contextlib import contextmanager
 from typing import Optional
@@ -144,3 +155,168 @@ class PhaseTracer:
                 ev["error"] = s.error
             out.append(ev)
         return out
+
+
+# -- device phases -----------------------------------------------------------
+
+SCOPE_PREFIX = "afl."
+# the blocks of one round of core/afl.py::afl_round (and of the distributed
+# step), in the order they run
+ROUND_PHASES = ("grads", "select", "compress", "aggregate", "state")
+# the scan engine's own blocks around the round: the in-scan minibatch
+# gather and the eval at a segment's end
+PHASES = ROUND_PHASES + ("sample", "eval")
+UNSCOPED = "unscoped"
+
+# the first ``afl.<phase>`` of an op name, at the start of a path component
+# or inside a transform's parentheses (``vmap(afl.grads)``)
+_PHASE_RE = re.compile(r"(?:^|[/(])" + re.escape(SCOPE_PREFIX) + r"(\w+)")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) .*\{$")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OP_NAME_RE = re.compile(r'metadata=\{[^\n]*?\bop_name="([^"]*)"')
+# ``metadata={...}``, whose quoted strings may hold braces
+_METADATA_RE = re.compile(r',? metadata=\{(?:[^{}"]|"[^"]*")*\}')
+# the stack-frame tables at the head of a compiled program's text
+_FRAMES_RE = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*",
+    re.MULTILINE)
+
+
+def phase(name: str):
+    """The named scope ``afl.<name>`` of one phase of the round."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; known: {PHASES}")
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def phase_of(op_name: str) -> str:
+    """The phase an op name (an instruction's ``op_name`` metadata, the
+    trace's ``tf_op``) lies in, or ``UNSCOPED``."""
+    m = _PHASE_RE.search(op_name)
+    return m.group(1) if m else UNSCOPED
+
+
+def op_phases(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: phase}`` for every instruction of a compiled
+    program's text (``jitted.lower(...).compile().as_text()``), fused
+    computations included.  An instruction takes the phase of the first
+    ``afl.*`` scope in its ``op_name``; one with none, such as a fusion
+    the compiler made up, takes the phase of the first instruction of the
+    computation it calls that has one; else ``UNSCOPED``.  The names are
+    those that a device trace gives its ops (``fusion.12``)."""
+    own: dict[str, str] = {}
+    calls: dict[str, str] = {}  # instruction -> computation it calls
+    first: dict[str, str] = {}  # computation -> its first scoped phase
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        if not m:
+            head = _COMP_RE.match(line)
+            if head:
+                comp = head.group(1)
+            continue
+        name = m.group(1)
+        op = _OP_NAME_RE.search(line)
+        own[name] = phase_of(op.group(1)) if op else UNSCOPED
+        if own[name] != UNSCOPED:
+            first.setdefault(comp, own[name])
+        called = _CALLS_RE.search(line)
+        if called:
+            calls[name] = called.group(1)
+    return {name: p if p != UNSCOPED else first.get(calls.get(name), p)
+            for name, p in own.items()}
+
+
+def strip_metadata(hlo_text: str) -> str:
+    """A compiled program's text without what scopes and source lines
+    change: each instruction's ``metadata={...}`` and the stack-frame
+    tables.  Two programs that differ only in their scopes strip equal."""
+    return _FRAMES_RE.sub("", _METADATA_RE.sub("", hlo_text))
+
+
+# -- compile counter ---------------------------------------------------------
+
+# JAX's monitoring events (jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py).  TRACE, LOWER and COMPILE come as time spans, one
+# per program: COMPILE covers the compile or its load from the persistent
+# cache, so CACHE_LOAD (a duration) lies inside a COMPILE span, and the
+# trace of a jitted function called while another traces lies inside that
+# one's TRACE span.  Seconds are therefore lengths of unions of spans,
+# never sums, and nothing is counted twice.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_STAGES = {TRACE_EVENT: "trace", LOWER_EVENT: "lower",
+           COMPILE_EVENT: "compile"}
+_COUNTS = {"trace": "traced", "lower": "lowered", "compile": "compiled"}
+_MARKS = {CACHE_HIT_EVENT: "cache_hits", CACHE_MISS_EVENT: "cache_misses"}
+
+
+def _union_s(spans) -> float:
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(spans):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+class CompileCounter:
+    """Programs JAX traced, lowered and compiled (or loaded from the
+    persistent cache) in this process, and the seconds it spent at each,
+    kept as time spans on ``time.time()``'s clock, each with the name of
+    the function JAX reported, so that a reader can count up to a moment
+    (``totals(until=...)``) or find the slowest programs (``spans``)."""
+
+    def __init__(self):
+        # stage, start, end, the function's name
+        self.spans: list[tuple[str, float, float, str]] = []
+        self.marks: list[tuple[str, float]] = []  # cache hit/miss, time
+        self._installed = False
+
+    def install(self) -> "CompileCounter":
+        """Register the listeners with ``jax.monitoring`` (once)."""
+        if not self._installed:
+            jax.monitoring.register_event_time_span_listener(self._span)
+            jax.monitoring.register_event_duration_secs_listener(
+                self._duration)
+            jax.monitoring.register_event_listener(self._event)
+            self._installed = True
+        return self
+
+    def _span(self, event, start, end, fun_name="", **_):
+        if event in _STAGES:
+            self.spans.append((_STAGES[event], start, end, fun_name))
+
+    def _duration(self, event, secs, **_):
+        if event == CACHE_LOAD_EVENT:
+            now = time.time()
+            self.spans.append(("cache_load", now - secs, now, ""))
+
+    def _event(self, event, **_):
+        if event in _MARKS:
+            self.marks.append((_MARKS[event], time.time()))
+
+    def totals(self, until: Optional[float] = None) -> dict:
+        """Counts and seconds of the spans that began before ``until``
+        (a ``time.time()``; all of them without).  ``jax_s`` is the time
+        inside any of JAX's trace, lower, compile or cache load."""
+        until = float("inf") if until is None else until
+        spans = [s for s in self.spans if s[1] < until]
+        out = {count: sum(s[0] == stage for s in spans)
+               for stage, count in _COUNTS.items()}
+        out.update({mark: sum(m == mark and t < until for m, t in self.marks)
+                    for mark in _MARKS.values()})
+        for stage in ("trace", "lower", "compile", "cache_load"):
+            out[stage + "_s"] = _union_s(
+                (a, b) for st, a, b, _ in spans if st == stage)
+        out["jax_s"] = _union_s((a, b) for _, a, b, _ in spans)
+        return out
+
+
+# one per process, counting from the first import of the program
+compiles = CompileCounter().install()

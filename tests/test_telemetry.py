@@ -11,6 +11,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -560,6 +561,141 @@ def test_tracer_nested_spans_and_exceptions():
     assert "parent" not in ev["outer"] and "parent" not in ev["after"]
     assert ev["outer"]["duration_s"] >= ev["inner"]["duration_s"]
     json.dumps(list(ev.values()))  # sink-ready with the new fields
+
+
+# ---------------------------------------------------------------------------
+# device phases and the compile counter
+# ---------------------------------------------------------------------------
+
+
+def _compiled_round(federation) -> str:
+    """The optimized HLO text of one ``afl_round`` at the tiny ResNet."""
+    from repro.core.afl import afl_init, afl_round
+
+    cfg, model, fl, shard, _ = federation
+    state = afl_init(model, cfg, fl, jax.random.key(0))
+    batch = shard.traced_batch(shard.seed_key(0), 0)
+    zeta = jnp.ones((fl.num_devices,))
+    policy = BL.ALL["mads"](model.num_params(), fl)
+    return afl_round.lower(
+        state, batch, zeta, 8.0 * zeta, jnp.full_like(zeta, 1e-9),
+        jnp.full_like(zeta, 100.0), model=model, cfg=cfg, fl=fl,
+        policy=policy).compile().as_text()
+
+
+def _running_instructions(hlo_text: str) -> list[str]:
+    """Instructions that run as ops of their own: none of a fused or an
+    applied computation (a reduction's or a sort's comparator), and no
+    parameter, constant or control flow."""
+    import re
+
+    inner = set(re.findall(r"\b(?:calls|to_apply)=%?([\w.\-]+)", hlo_text))
+    skip = {"parameter", "constant", "while", "conditional", "call"}
+    comp, out = None, []
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\s([\w\-]+)\(",
+                     line)
+        if m and comp not in inner and m.group(2) not in skip:
+            out.append(m.group(1))
+    return out
+
+
+def test_phase_names_and_op_names():
+    from repro.telemetry import tracing as T
+
+    assert T.PHASES[:5] == T.ROUND_PHASES
+    with pytest.raises(ValueError):
+        T.phase("nope")
+    assert T.phase_of("jit(run)/while/body/afl.grads/vmap(jvp())/mul") \
+        == "grads"
+    assert T.phase_of("jit(f)/vmap(afl.state)/sub") == "state"
+    assert T.phase_of("jit(f)/while/body/add") == T.UNSCOPED
+    text = ('%fused (p: f32[2]) -> f32[2] {\n'
+            '  %x = f32[2] add(p, p), metadata={op_name="jit(f)/afl.compress/add"}\n'
+            '}\n\nENTRY %main (a: f32[2]) -> f32[2] {\n'
+            '  %a = f32[2] parameter(0)\n'
+            '  %fusion.3 = f32[2] fusion(%a), kind=kLoop, calls=%fused\n'
+            '  ROOT %sort.1 = f32[2] sort(%fusion.3), metadata={op_name='
+            '"jit(f)/afl.select/sort" source_file="a{b}.py"}\n}\n')
+    assert T.op_phases(text) == {"x": "compress", "a": T.UNSCOPED,
+                                 "fusion.3": "compress", "sort.1": "select"}
+    assert "metadata" not in T.strip_metadata(text)
+    assert "sort(%fusion.3)\n" in T.strip_metadata(text)
+
+
+def test_op_phases_cover_the_compiled_round(federation):
+    """Every phase of the round names instructions of the compiled program,
+    and nine in ten of the instructions that run carry a phase."""
+    from repro.telemetry import tracing as T
+
+    text = _compiled_round(federation)
+    phases = T.op_phases(text)
+    assert set(T.ROUND_PHASES) <= set(phases.values())
+    running = _running_instructions(text)
+    scoped = [n for n in running if phases[n] != T.UNSCOPED]
+    assert len(running) > 100
+    assert len(scoped) >= 0.9 * len(running)
+
+
+def test_scopes_leave_the_compiled_round_unchanged(federation, monkeypatch):
+    """The scopes change only metadata: with ``phase`` a no-op the
+    optimized HLO, metadata stripped, is the same text."""
+    import contextlib
+
+    from repro.core import afl
+    from repro.telemetry import tracing as T
+
+    scoped = _compiled_round(federation)
+    monkeypatch.setattr(afl, "phase", lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    try:
+        plain = _compiled_round(federation)
+    finally:
+        jax.clear_caches()
+    assert "afl.grads" in scoped and "afl.grads" not in plain
+    assert T.strip_metadata(scoped) == T.strip_metadata(plain)
+
+
+def test_compile_counter_counts_a_fresh_jit():
+    from repro.telemetry.tracing import compiles
+
+    x = jnp.arange(7.0).block_until_ready()
+    before = compiles.totals()
+    t0 = time.time()
+    jax.jit(lambda x: x * 3.0 + 1.0)(x).block_until_ready()
+    after = compiles.totals()
+    assert after["lowered"] - before["lowered"] == 1
+    assert after["compiled"] - before["compiled"] == 1
+    assert after["traced"] - before["traced"] >= 1
+    assert after["jax_s"] > before["jax_s"]
+    assert after["jax_s"] - before["jax_s"] <= time.time() - t0
+    # nothing began after now; the counter counts up to a moment
+    assert compiles.totals(until=time.time()) == after
+    assert compiles.totals(until=t0)["lowered"] == before["lowered"]
+    json.dumps(after)  # sink-ready
+
+
+def test_compile_counter_unions_nested_spans():
+    from repro.telemetry.tracing import CompileCounter, TRACE_EVENT, \
+        COMPILE_EVENT, CACHE_LOAD_EVENT, LOWER_EVENT
+
+    c = CompileCounter()  # not installed: fed by hand
+    c._span(TRACE_EVENT, 0.0, 4.0)
+    c._span(TRACE_EVENT, 1.0, 2.0)  # an inner jit traced inside the outer
+    c._span(LOWER_EVENT, 4.0, 5.0)
+    c._span(COMPILE_EVENT, 6.0, 8.0)
+    c._span("/jax/other", 0.0, 100.0)
+    tot = c.totals()
+    assert (tot["traced"], tot["lowered"], tot["compiled"]) == (2, 1, 1)
+    assert (tot["trace_s"], tot["lower_s"], tot["compile_s"]) == (4, 1, 2)
+    assert tot["jax_s"] == 7.0
+    assert c.totals(until=5.0)["compiled"] == 0
+    c._duration(CACHE_LOAD_EVENT, 0.5)
+    assert c.totals()["cache_load_s"] == pytest.approx(0.5)
 
 
 # ---------------------------------------------------------------------------
