@@ -62,6 +62,20 @@ class DataShard:
     Sampling is uniform-with-replacement over each device's true row count
     (padding rows are never drawn), unlike ``DeviceLoader``'s
     epoch-permutation semantics — both are unbiased samplers of D_n.
+
+    The data and the per-device row counts are held in JAX array
+    references (``jax.new_ref``, one per leaf) and read with ``ref[...]``.
+    ``jax.jit`` embeds closed-over arrays in the program as constants, so
+    a program that sampled from plain arrays would differ with every
+    dataset, and so would its persistent-cache key; closed-over refs are
+    passed to the program as arguments instead, so one compiled program
+    serves every seed's data.  Nothing ever writes the refs.
+
+    A ref is committed to the one device that holds it.  Under a mesh
+    (``shard_map``, ``jax.set_mesh``), whose inputs live on other devices
+    too, ``traced_batch`` reads the refs' values as constants instead;
+    called outside any trace it returns uncommitted arrays, as it did when
+    the data were plain arrays.
     """
 
     def __init__(self, device_arrays: list[dict], batch_size: int,
@@ -70,16 +84,26 @@ class DataShard:
             [len(next(iter(d.values()))) for d in device_arrays], np.int32
         )
         m = int(counts.max())
-        self.data = {
-            k: jnp.asarray(np.stack([
+        self._data = {
+            k: jax.new_ref(jnp.asarray(np.stack([
                 np.resize(d[k], (m,) + d[k].shape[1:]) for d in device_arrays
-            ]))
+            ])))
             for k in device_arrays[0]
         }
-        self.counts = jnp.asarray(counts)
+        self._counts = jax.new_ref(jnp.asarray(counts))
         self.num_devices = len(device_arrays)
         self.batch_size = batch_size
         self.key = jax.random.key(seed)
+
+    @property
+    def data(self) -> dict:
+        """The (N, M, ...) padded arrays, read from their refs."""
+        return {k: r[...] for k, r in self._data.items()}
+
+    @property
+    def counts(self):
+        """(N,) true row count of each device, read from its ref."""
+        return self._counts[...]
 
     def __len__(self):
         return self.num_devices
@@ -90,14 +114,21 @@ class DataShard:
 
     def traced_batch(self, key, r):
         """(N, B, ...) minibatch for round r — jnp-traceable."""
+        if jax.sharding.get_abstract_mesh().empty:
+            data, counts = self.data, self.counts
+        else:
+            with jax.ensure_compile_time_eval():
+                data, counts = jax.device_get((self.data, self.counts))
         kr = jax.random.fold_in(key, r)
         idx = jax.random.randint(
-            kr, (self.num_devices, self.batch_size), 0, self.counts[:, None]
+            kr, (self.num_devices, self.batch_size), 0, counts[:, None]
         )
-        return jax.tree.map(
-            lambda a: jax.vmap(lambda rows, ii: rows[ii])(a, idx), self.data
+        batch = jax.tree.map(
+            lambda a: jax.vmap(lambda rows, ii: rows[ii])(a, idx), data
         )
-
+        if isinstance(idx, jax.core.Tracer):
+            return batch
+        return jax.tree.map(lambda v: jnp.asarray(jax.device_get(v)), batch)
 
 
 def prestack_batches(loader, rounds: int):

@@ -1,10 +1,19 @@
 """Compiled experiment engine: scan/loop equivalence, grids, seed-vmap."""
+import os
+import pathlib
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import FLConfig, get_config
 from repro.core import baselines as BL
-from repro.core.runner import run_afl
+from repro.core.afl import afl_init
+from repro.core.runner import build_provider, run_afl, sample_budgets
 from repro.data import DeviceLoader
 from repro.experiments import (
     DataShard,
@@ -16,11 +25,12 @@ from repro.experiments import (
     run_seed_batch,
 )
 from repro.experiments.grid import engine_policy
-from repro.experiments.scan_engine import eval_points
+from repro.experiments.scan_engine import eval_points, make_run_fn
 from repro.launch.train import build_device_data
 from repro.models.registry import build_model
 
 ROUNDS, EVERY = 8, 4
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +126,174 @@ def test_seed_vmap_matches_independent(federation):
         _assert_hist_close(ind.history, res.history)
     # different seeds actually ran different scenarios
     assert batch[0].history["uploads"] != batch[1].history["uploads"]
+
+
+# ---------------------------------------------------------------------------
+# DataShard: the data are arguments of the program, not constants
+# ---------------------------------------------------------------------------
+
+
+def _segment_lowered(cfg, model, fl, seed: int, rounds: int = 2):
+    """The segment program of a federation whose data come from ``seed``,
+    lowered as the benchmark builds it (``make_run_fn`` sampling from a
+    ``DataShard``), and that shard's data."""
+    dev, ev = build_device_data(cfg, fl, train_n=160, eval_n=16, seed=seed)
+    shard = DataShard(dev, fl.batch_size, seed=seed)
+    policy = BL.ALL["mads"](model.num_params(), fl)
+    run = jax.jit(make_run_fn(model, cfg, fl, policy, rounds=rounds,
+                              eval_every=rounds, sampler=shard.traced_batch))
+    zeta, tau, h2 = build_provider(fl, "mads", None, rounds, seed).schedule()
+    lowered = run.lower(
+        afl_init(model, cfg, fl, jax.random.key(seed)), jnp.asarray(zeta),
+        jnp.asarray(tau, jnp.float32), jnp.asarray(h2, jnp.float32),
+        sample_budgets(fl, seed), {k: jnp.asarray(v) for k, v in ev.items()},
+        shard.seed_key(seed), {}, {})
+    return lowered.as_text(debug_info=False), shard
+
+
+def test_segment_program_does_not_depend_on_the_data(federation):
+    """Two seeds' data lower to the same program text, which holds no
+    constant as large as the smallest data leaf (the labels)."""
+    cfg, model, fl, _, _ = federation
+    a, shard = _segment_lowered(cfg, model, fl, seed=12)
+    b, _ = _segment_lowered(cfg, model, fl, seed=21)
+    assert a == b
+    smallest = min(v.nbytes for v in shard.data.values())
+    literals = re.findall(r"dense<[^>]*>", a)
+    assert max(map(len, literals)) < 2 * smallest
+
+
+def test_traced_batch_matches_numpy_gather(federation):
+    """Eager and jitted draws equal a NumPy gather of the padded per-device
+    rows at the same indices; the eager draw is not committed to a device."""
+    cfg, model, fl, dev, ev = federation
+    shard = DataShard(dev, fl.batch_size, seed=0)
+    key, r = shard.seed_key(3), 5
+    counts = np.array([len(d["labels"]) for d in dev], np.int32)
+    idx = np.asarray(jax.random.randint(
+        jax.random.fold_in(key, r), (len(dev), fl.batch_size), 0,
+        counts[:, None]))
+    assert np.all(idx < counts[:, None])  # padding rows are never drawn
+    m = int(counts.max())
+    want = {k: np.stack([np.resize(d[k], (m,) + d[k].shape[1:])[i]
+                         for d, i in zip(dev, idx)]) for k in dev[0]}
+    eager = shard.traced_batch(key, r)
+    jitted = jax.jit(shard.traced_batch)(key, r)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(eager[k]), want[k])
+        np.testing.assert_array_equal(np.asarray(jitted[k]), want[k])
+        assert not eager[k].committed
+
+
+ENGAGED = r"""
+import sys
+import jax
+import jax.numpy as jnp
+from repro.launch.cache import use_compile_cache
+from repro.telemetry.tracing import compiles
+use_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.configs import FLConfig, get_config
+from repro.core import baselines as BL
+from repro.core.afl import afl_init
+from repro.core.runner import build_provider, sample_budgets
+from repro.experiments import DataShard
+from repro.experiments.scan_engine import make_run_fn
+from repro.launch.train import build_device_data
+from repro.models.registry import build_model
+
+seed = int(sys.argv[1])
+cfg = get_config("resnet9-cifar10").replace(d_model=4)
+model = build_model(cfg)
+fl = FLConfig(num_devices=4, rounds=2, batch_size=8, seed=seed)
+dev, ev = build_device_data(cfg, fl, train_n=160, eval_n=16, seed=seed)
+shard = DataShard(dev, fl.batch_size, seed=seed)
+policy = BL.ALL["mads"](model.num_params(), fl)
+run = jax.jit(make_run_fn(model, cfg, fl, policy, rounds=2, eval_every=2,
+                          sampler=shard.traced_batch))
+zeta, tau, h2 = build_provider(fl, "mads", None, 2, seed).schedule()
+state = afl_init(model, cfg, fl, jax.random.key(seed))
+jax.block_until_ready(run(
+    state, jnp.asarray(zeta), jnp.asarray(tau, jnp.float32),
+    jnp.asarray(h2, jnp.float32), sample_budgets(fl, seed),
+    {k: jnp.asarray(v) for k, v in ev.items()}, shard.seed_key(seed), {}, {}))
+t = compiles.totals()
+segment = sum(s[0] == "compile" and s[3] == "jit(run)" for s in compiles.spans)
+print(t["cache_hits"], t["cache_misses"], segment)
+"""
+
+
+def test_fresh_seed_loads_the_segment_program_from_the_cache(tmp_path):
+    """A second process with another seed's data compiles nothing afresh:
+    the segment program, among every other, is a hit in the persistent
+    cache that the first process filled."""
+    from repro.launch import cache
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
+    env[cache.ENV] = str(tmp_path)
+
+    def run(seed):
+        out = subprocess.run([sys.executable, "-c", ENGAGED, str(seed)],
+                             env=env, capture_output=True, text=True,
+                             timeout=600)
+        assert out.returncode == 0, out.stderr[-3000:]
+        return [int(v) for v in out.stdout.split()[-3:]]
+
+    hits, misses, segment = run(12)
+    assert (hits, segment) == (0, 1) and misses > 0
+    assert run(21) == [misses, 0, 1]
+
+
+HOST_MESH = r"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.configs import FLConfig, get_config
+from repro.experiments import DataShard, run_afl_scanned, run_seed_batch
+from repro.launch.mesh import make_seed_mesh
+from repro.launch.train import build_device_data
+from repro.models.registry import build_model
+
+assert jax.device_count() == 4, jax.devices()
+cfg = get_config("resnet9-cifar10").replace(d_model=4)
+model = build_model(cfg)
+fl = FLConfig(num_devices=4, rounds=4, batch_size=8, learning_rate=0.02,
+              mean_contact=6.0, mean_intercontact=30.0,
+              energy_budget=(40.0, 80.0))
+dev, ev = build_device_data(cfg, fl, train_n=160, eval_n=64, seed=0)
+shard = DataShard(dev, fl.batch_size, seed=0)
+mesh = make_seed_mesh(4)
+assert mesh is not None and mesh.devices.size == 4
+seeds = [0, 1, 2, 3]
+batch = run_seed_batch(model, cfg, fl, "mads", shard, ev, seeds=seeds,
+                       rounds=4, eval_every=2, mesh=mesh)
+for res, seed in zip(batch, seeds):
+    ind = run_afl_scanned(model, cfg, fl, "mads", shard, ev, rounds=4,
+                          eval_every=2, seed=seed)
+    for k in ind.history:
+        np.testing.assert_allclose(np.asarray(res.history[k]),
+                                   np.asarray(ind.history[k]), rtol=2e-4,
+                                   atol=1e-5, err_msg=f"seed {seed} {k}")
+# an eager draw joins arrays placed on the mesh
+on_mesh = jax.device_put(jnp.ones(4), NamedSharding(mesh, P("seed")))
+rows = shard.traced_batch(shard.seed_key(0), 0)["labels"]
+assert float(jnp.sum(on_mesh * rows[:, 0])) == float(jnp.sum(rows[:, 0]))
+print("HOST_MESH_OK")
+"""
+
+
+def test_seed_batch_on_host_mesh_matches_independent():
+    """With the shard's data held in refs, seeds sharded over a 4-device
+    host mesh still equal independent one-device runs of each seed."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", HOST_MESH], env=env,
+                         capture_output=True, text=True, timeout=1200)
+    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]
+    assert "HOST_MESH_OK" in out.stdout
 
 
 # ---------------------------------------------------------------------------
