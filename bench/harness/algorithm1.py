@@ -241,7 +241,9 @@ def run(c, loss, w0, data, counts, keys, ckey, schedule, budgets, *,
     segment, per-leaf norms of the cumulative gradients after the first,
     and after the last of the global and local models' change from
     ``w0``, of the error memories and cumulative gradients, the rounds of
-    last contact, and the energy spent."""
+    last contact, and the energy spent: each client's cumulative energy
+    E_n is state of the round like its queue, kept in ``dtype``, and the
+    clients' totals are summed in float64."""
     n, rounds = len(data), schedule[0].shape[0]
     w0 = jax.tree.map(lambda a: jnp.asarray(a, dtype), w0)
     longest = int(max(counts))
@@ -265,8 +267,9 @@ def run(c, loss, w0, data, counts, keys, ckey, schedule, budgets, *,
     rows_fn = jax.jit(lambda key, j: jax.random.randint(
         jax.random.fold_in(key, j), (n, c["batch"]), 0,
         jnp.asarray(counts)[:, None]))
+    spent = jnp.zeros((n,), dtype)
     out = {"count": []}
-    r, spent = 0, []
+    r = 0
     for i in range(steps):
         seg_counts = []
         for j in range(segment):
@@ -280,7 +283,7 @@ def run(c, loss, w0, data, counts, keys, ckey, schedule, budgets, *,
                 jnp.asarray(tau, dtype), jnp.asarray(h2, dtype), budgets,
                 jax.random.split(sub, n))
             kappa[zeta > 0] = r
-            spent.append(energy)
+            spent = spent + energy
             seg_counts.append(count)
         out["count"].append(float(np.sum(jax.device_get(seg_counts),
                                          dtype=np.float64)))

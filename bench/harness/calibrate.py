@@ -16,11 +16,18 @@ segments; an ingest cell runs a window of ``--seconds`` at its rate.
 sustains.  One JSON line per reading on stdout, with the readings of both
 sides, so that any number can be worked out again from it; it is not part
 of a benchmark run.
+
+    python3 bench/harness/calibrate.py --workload resnet9.mads.n20 \\
+        --set-limits readings.jsonl
+
+prints the limits that ``limits_from`` sets from such lines (the rule is in
+its docstring), without a chip.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -30,8 +37,96 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 
+# Rule (a): every limit is at least MARGIN times the largest sound reading.
+# Rule (b): the control and each fault pass some limit by PASS times.
+MARGIN, PASS = 2.0, 1.5
+# How far a kind's least reading of a number lies above the sound maximum
+# for it to set that number's upper reading: the control and a state left
+# unchanged three times, any other fault ten times.
+FAR = {"control": 3.0, "unchanged_state": 3.0}
+FAULT_FAR = 10.0
+# The numbers the rule may hold: the worst-leaf gaps, the count, the
+# energy and the last contacts.  The whole-model gaps (``<name>_all``) are
+# read but not held: on sound seeds their largest reading is ten times
+# their median, against three to five times for the worst leaf, and every
+# kind that passes one of them passes a worst-leaf number too.
+HELD = ("count", "energy", "kappa")
+# The sound program sets the lower readings.  Neither the witness at the
+# highest precision nor the benchmark's own runs on seeds held out of the
+# calibration (``held_out``, their checked numbers) set anything.
+SOUND = "program"
+ASIDE = ("program_highest", "held_out")
+
+
 def seeds(text: str) -> list[int]:
     return [int(x) for x in text.split(",") if x]
+
+
+def passes(value: float, limit: float) -> bool:
+    """A reading fails a limit by rule (b)'s factor (any reading over a
+    limit of 0 does)."""
+    return value > limit and value >= PASS * limit
+
+
+def two_digits(x: float) -> float:
+    """``x`` cut down to two significant digits."""
+    e = math.floor(math.log10(x)) - 1
+    return float(f"{math.floor(x / 10 ** e + 1e-9)}e{e}")
+
+
+def limits_from(readings: list[dict]) -> dict:
+    """The limits the rule sets from calibration lines (``kind``, ``seed``,
+    ``numbers``).
+
+    For each number, the lower reading L is the largest sound one, and
+    the upper U the least reading of the kinds (the control and the
+    faults) whose least reading lies ``FAR`` (``FAULT_FAR``) times above
+    L.  A number with no U gets no limit; one that reads 0 on every sound
+    seed is exact and gets 0; else the limit lies between MARGIN x L and
+    U / PASS, six tenths of the way up in logarithm.  A kind that sets no
+    U is held by the worst-leaf numbers (``compare.LEAFWISE``, where a
+    changed gradient shows most): each of their limits drops to c x L,
+    with c the largest multiple, one for them all and at least MARGIN, by
+    which that kind passes one of them by PASS x on every seed.  Limits
+    are cut down to two digits.  Raises where (a) or (b) fails."""
+    from bench.harness.compare import LEAFWISE
+
+    sound = [r["numbers"] for r in readings if r["kind"] == SOUND]
+    faulty = [r for r in readings if r["kind"] not in (SOUND, *ASIDE)]
+    kinds = {r["kind"] for r in faulty}
+    lower = {name: max(n[name] for n in sound) for name in LEAFWISE + HELD}
+
+    def least(kind, name):
+        return min(r["numbers"][name] for r in faulty if r["kind"] == kind)
+
+    limits, held = {}, set()
+    for name, low in lower.items():
+        uppers = {k: least(k, name) for k in kinds}
+        uppers = {k: v for k, v in uppers.items()
+                  if v > 0 and v >= FAR.get(k, FAULT_FAR) * low}
+        if not uppers:
+            continue
+        held |= set(uppers)
+        lo, hi = MARGIN * low, min(uppers.values()) / PASS
+        limits[name] = 0 if low == 0 else two_digits(lo ** 0.4 * hi ** 0.6)
+    for kind in sorted(kinds - held):
+        c = min(max(r["numbers"][name] / lower[name] for name in LEAFWISE)
+                for r in faulty if r["kind"] == kind) / PASS
+        c = math.floor(c * 10) / 10
+        if c < MARGIN:
+            raise ValueError(f"{kind} lies within {MARGIN * PASS}x the sound "
+                             f"maximum of every worst-leaf number")
+        for name in LEAFWISE:
+            limits[name] = min(limits.get(name, math.inf),
+                               two_digits(c * lower[name]))
+    for name, limit in limits.items():
+        if limit < MARGIN * lower[name]:
+            raise ValueError(f"{name}: limit {limit} under rule (a)")
+    for r in faulty:
+        if not any(passes(r["numbers"][k], v) for k, v in limits.items()):
+            raise ValueError(f"{r['kind']} on seed {r['seed']} passes no "
+                             f"limit by {PASS}x: {r['numbers']}")
+    return limits
 
 
 def main(argv=None) -> int:
@@ -46,7 +141,14 @@ def main(argv=None) -> int:
                          "precision too (a witness, not a limit)")
     ap.add_argument("--rates", default="")
     ap.add_argument("--seconds", type=float, default=5.0)
+    ap.add_argument("--set-limits", default="",
+                    help="a file of calibration lines to set limits from")
     args = ap.parse_args(argv)
+    if args.set_limits:
+        with open(args.set_limits) as f:
+            readings = [json.loads(line) for line in f if line.strip()]
+        print(json.dumps({"limits": limits_from(readings)}, indent=2))
+        return 0
 
     import jax
     import jax.numpy as jnp

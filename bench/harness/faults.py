@@ -28,7 +28,23 @@ def unchanged_state(model, policy):
     return model, policy, lambda old, new: old
 
 
-FAULTS = {"half_batch": half_batch, "unchanged_state": unchanged_state}
+def double_energy(model, policy):
+    """The upload decision reports twice the energy each upload spends, so
+    the clients' energy and their virtual queues take twice the spend."""
+    select = type(policy).select
+
+    def doubled(self, *args):
+        k, p, energy = select(self, *args)
+        return k, p, 2.0 * energy
+
+    cls = type("DoubleEnergy", (type(policy),), {"select": doubled})
+    fields = {f.name: getattr(policy, f.name)
+              for f in dataclasses.fields(policy)}
+    return model, cls(**fields), None
+
+
+FAULTS = {"half_batch": half_batch, "unchanged_state": unchanged_state,
+          "double_energy": double_energy}
 
 
 def altered_upload(b):

@@ -47,7 +47,8 @@ def test_sound_program_is_correct(cell):
                                    "memory_peak_bytes"}
 
 
-@pytest.mark.parametrize("name", ["half_batch", "unchanged_state"])
+@pytest.mark.parametrize("name", ["half_batch", "unchanged_state",
+                                  "double_energy"])
 def test_fault_is_not_correct(cell, name):
     from bench.harness.faults import FAULTS
 
